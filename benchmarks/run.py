@@ -135,6 +135,9 @@ def main() -> None:
                     help="diff each suite against BASELINE_DIR/BENCH_<suite>"
                          ".json and exit non-zero on regression")
     args = ap.parse_args()
+    from repro.compile_cache import enable_compile_cache
+
+    print(f"compile cache: {enable_compile_cache()}", file=sys.stderr)
     out_dir = pathlib.Path(args.out_dir)
     out_dir.mkdir(parents=True, exist_ok=True)
     meta = {"commit": git_commit(), "smoke": bool(args.smoke)}

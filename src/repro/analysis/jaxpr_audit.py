@@ -4,11 +4,11 @@ Every entry point that claims to be device-resident is registered here with a
 **purity tier** and a builder that constructs production-shaped example
 inputs (T = 230 -- the paper's 10 x 23 grid -- realistic fleet/batch sizes).
 The auditor lowers the entry to its ClosedJaxpr (no compilation, no
-execution) and walks every equation, recursing through ``pjit`` /
+execution) and walks every equation, recursing through ``jit`` /
 ``while`` / ``scan`` / ``cond`` sub-jaxprs, checking the tier's contract:
 
   host-callback      ``pure_callback`` / ``io_callback`` / ``debug_callback``
-                     (debug prints lower to the latter) anywhere in a device
+                     / ``debug_print`` (``jax.debug.print``) anywhere in a device
                      tier: each is a host round-trip in a path that promises
                      zero host syncs.
   float64-leak       a non-weak float64 intermediate on a device tier.
@@ -51,24 +51,13 @@ import numpy as np
 
 from . import Finding
 
-try:  # the jaxpr types moved between jax versions
-    from jax.extend import core as jcore  # noqa: F401  (newer releases)
-    _Jaxpr = jcore.Jaxpr
-    _ClosedJaxpr = getattr(jcore, "ClosedJaxpr", None)
-except Exception:  # pragma: no cover
-    jcore = None
-    _Jaxpr = None
-    _ClosedJaxpr = None
-if _Jaxpr is None or _ClosedJaxpr is None:  # pragma: no cover
-    import jax.core as _jax_core
-
-    _Jaxpr = _jax_core.Jaxpr
-    _ClosedJaxpr = _jax_core.ClosedJaxpr
+from jax.extend.core import ClosedJaxpr as _ClosedJaxpr
+from jax.extend.core import Jaxpr as _Jaxpr
 
 #: primitives that are host round-trips by construction
 CALLBACK_PRIMITIVES = frozenset(
-    {"pure_callback", "io_callback", "debug_callback", "callback",
-     "outside_call", "host_callback_call"})
+    {"pure_callback", "io_callback", "debug_callback", "debug_print",
+     "callback", "outside_call", "host_callback_call"})
 
 #: per-platform on-chip scratch budget for one Pallas kernel's resident
 #: blocks. TPU VMEM is ~16 MiB/core; the budget keeps headroom for compiler
@@ -570,7 +559,7 @@ def _sub_jaxprs(eqn):
 
 
 def iter_eqns(jaxpr):
-    """Every equation of ``jaxpr``, recursing into sub-jaxprs (pjit, control
+    """Every equation of ``jaxpr``, recursing into sub-jaxprs (jit, control
     flow, pallas kernel bodies -- anything carrying a jaxpr in its params)."""
     for eqn in jaxpr.eqns:
         yield eqn
@@ -623,7 +612,7 @@ def _check_eqns(entry: HotEntry, closed) -> list[Finding]:
 # -- donation ------------------------------------------------------------------
 
 def _check_donation(entry: HotEntry, closed) -> list[Finding]:
-    """Donation declared on a pjit whose outputs can never absorb the buffer.
+    """Donation declared on a jit whose outputs can never absorb the buffer.
 
     A donated input aliases an output only when some output matches its
     shape/dtype; a donated invar with no match is a contract violation (the
@@ -654,7 +643,7 @@ def _check_donation(entry: HotEntry, closed) -> list[Finding]:
             for eqn in iter_eqns(closed.jaxpr)):
         findings.append(Finding(
             "donation", "donation-missing", entry.name,
-            "entry is registered as donating but no pjit declares donation"))
+            "entry is registered as donating but no jit declares donation"))
     return findings
 
 
@@ -684,6 +673,13 @@ def _block_mappings(eqn):
     return gm, getattr(gm, "block_mappings", ())
 
 
+def _block_size(d) -> "int | None":
+    """One BlockSpec dim as an int: ``Blocked(block_size=n)`` (or a bare
+    int) gives n; squeezed and other non-blocked dims give None."""
+    d = getattr(d, "block_size", d)
+    return int(d) if isinstance(d, (int, np.integer)) else None
+
+
 def pallas_budget_findings(entry: HotEntry, closed) -> tuple[list[Finding], list[dict]]:
     """VMEM residency + grid-divisibility for every pallas_call in the trace.
 
@@ -702,19 +698,17 @@ def pallas_budget_findings(entry: HotEntry, closed) -> tuple[list[Finding], list
             continue
         total = 0
         for bm in mappings:
-            shape_dtype = getattr(bm, "array_shape_dtype", None)
-            block = [d for d in bm.block_shape if isinstance(d, (int, np.integer))]
-            if shape_dtype is None:  # pragma: no cover
-                continue
-            itemsize = np.dtype(shape_dtype.dtype).itemsize
-            total += int(np.prod(block, dtype=np.int64)) * itemsize
-            arr = shape_dtype.shape
-            for a, b in zip(arr, bm.block_shape):
-                if isinstance(b, (int, np.integer)) and b > 0 and a % b:
+            aval = bm.array_aval
+            block = [_block_size(d) for d in bm.block_shape]
+            itemsize = np.dtype(aval.dtype).itemsize
+            total += int(np.prod([b or 1 for b in block], dtype=np.int64)) * itemsize
+            arr = aval.shape
+            for a, b in zip(arr, block):
+                if b and a % b:
                     findings.append(Finding(
                         "vmem", "grid-divisibility", entry.name,
                         f"array dim {a} not divisible by block dim {b} "
-                        f"(array {list(arr)}, block {list(bm.block_shape)})"))
+                        f"(array {list(arr)}, block {block})"))
         sites.append({"entry": entry.name, "grid": list(getattr(gm, "grid", ())),
                       "resident_bytes": total, "budget_bytes": budget})
         if total > budget:

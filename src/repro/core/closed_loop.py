@@ -97,7 +97,6 @@ class ClosedLoopConfig:
     solo_eps: float = 0.05
     est_max_lost_frac: float = 0.5
     use_pallas: bool = False
-    interpret: bool = True
     # thread an obs.MetricFrame through the carry (engine event metrics +
     # per-segment split/evict/requeue/ring/D-refresh accounting); off keeps
     # LoopCarry.metrics = None and the compiled program byte-identical
@@ -315,7 +314,10 @@ def run_closed_loop(
                     _localize_block(rblock, lo) if sharded else rblock,
                     lr=config.lr, decay=config.decay, step_damp=config.step_damp,
                     solo_eps=config.solo_eps, max_lost_frac=config.est_max_lost_frac,
-                    use_pallas=config.use_pallas, interpret=config.interpret,
+                    use_pallas=config.use_pallas,
+                    # the Pallas scatter interprets off-TPU only: on the
+                    # chip it always lowers through Mosaic
+                    interpret=jax.default_backend() != "tpu",
                     sparse_tables=True)
                 if sharded:
                     used = axis.psum(used)

@@ -38,7 +38,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import TYPE_CHECKING, Callable, Literal, Sequence
+from typing import TYPE_CHECKING, Callable, Literal, NamedTuple, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -67,6 +67,7 @@ from ..telemetry.log import (
 
 if TYPE_CHECKING:
     from ..fleet.controller import FleetController, HealthEvent
+    from .closed_loop import ClosedLoopConfig, LoopCarry, SegmentIn
     from ..telemetry.drift import DriftSchedule
 
 Backend = Literal["auto", "jax", "numpy"]
@@ -464,6 +465,19 @@ class AdaptiveResult:
         return int(sum(self.n_obs))
 
 
+class DeviceLoopInputs(NamedTuple):
+    """One device-loop run, packed: ``run_closed_loop(*inputs[:7])``."""
+
+    cluster: PackedCluster
+    dyn_stack: PackedDynamics  # stacked [U, m, ...] per-segment worlds
+    Lp_t: jax.Array  # f32[m, T, T] target-major L priors
+    logb_priors: jax.Array  # f32[m, T]
+    carry: "LoopCarry"
+    xs: "SegmentIn"
+    config: "ClosedLoopConfig"
+    t0s: tuple[float, ...]  # first arrival time per real segment
+
+
 class AdaptiveEngine:
     """The closed-loop front-end: place from *estimated* dynamics, observe the
     (simulated) world, refresh the estimate, repeat.
@@ -795,34 +809,16 @@ class AdaptiveEngine:
                               tuple(health), metrics=frame, decisions=ring)
 
     # -- the fused device-resident loop -----------------------------------
-    def _run_device_loop(
+    def _pack_device_loop(
         self, arrivals: Sequence[tuple[float, Workload]], segments: int,
         *, metrics: bool = False, record: bool = False,
-    ) -> AdaptiveResult:
-        """One ``run_closed_loop`` dispatch for the whole multi-segment run.
-
-        Host work is strictly prologue (pack arrivals/dynamics, snapshot the
-        live estimator/detector/pool state into the scan carry) and epilogue
-        (unpack per-segment results, mirror the final carry back into the
-        host objects via ``FleetController.adopt_device_outcome`` /
-        ``PooledEstimatorBank.adopt_rows``). Per-segment ``EngineResult``s
-        carry no ``observations``/``stream_block``: the telemetry was
-        consumed inside the program (the ring holds the bounded history).
-
-        The three host phases are wrapped in ``repro.obs.trace`` spans
-        (``closed_loop.pack`` / ``.dispatch`` / ``.epilogue``) so profiler
-        traces and span logs separate packing and adoption cost from the
-        blocking dispatch (which includes compilation on a cold cache).
-        With ``metrics=True`` the MetricFrame rides the scan carry and the
-        merged run frame is returned on ``AdaptiveResult.metrics``.
-        """
+    ) -> "DeviceLoopInputs":
+        """The device loop's prologue (the ``closed_loop.pack`` span):
+        validate the run, pack arrivals and dynamics, and snapshot the live
+        estimator/detector/pool state into the scan carry -- everything
+        one ``run_closed_loop`` call consumes."""
         from ..fleet.detect import CusumState
-        from .closed_loop import (
-            ClosedLoopConfig,
-            LoopCarry,
-            SegmentIn,
-            run_closed_loop,
-        )
+        from .closed_loop import ClosedLoopConfig, LoopCarry, SegmentIn
 
         if not self.stream:
             raise ValueError("device_loop=True requires stream mode "
@@ -903,7 +899,7 @@ class AdaptiveEngine:
             est_h = dict(
                 lr=h["lr"], decay=h["decay"], step_damp=h["step_damp"],
                 solo_eps=h["solo_eps"], est_max_lost_frac=h["max_lost_frac"],
-                use_pallas=h["use_pallas"], interpret=h["interpret"])
+                use_pallas=h["use_pallas"])
             frame0 = obs_metrics.zeros(m) if metrics else None
             rec0 = self._decision_ring().state if record else None
             fc = self.fleet
@@ -949,11 +945,38 @@ class AdaptiveEngine:
                 arr_time=jnp.asarray(arr_time), arr_type=jnp.asarray(arr_type),
                 arr_bytes=jnp.asarray(arr_bytes), dyn_idx=jnp.asarray(dyn_idx),
                 seg_valid=jnp.asarray(np.arange(S_cap) < segments))
+        return DeviceLoopInputs(cluster, dyn_stack, Lp_t, logb_priors, carry0,
+                                xs, config, tuple(t0s))
 
+    def _run_device_loop(
+        self, arrivals: Sequence[tuple[float, Workload]], segments: int,
+        *, metrics: bool = False, record: bool = False,
+    ) -> AdaptiveResult:
+        """One ``run_closed_loop`` dispatch for the whole multi-segment run.
+
+        Host work is strictly prologue (:meth:`_pack_device_loop`) and
+        epilogue (unpack per-segment results, mirror the final carry back
+        into the host objects via ``FleetController.adopt_device_outcome`` /
+        ``PooledEstimatorBank.adopt_rows``). Per-segment ``EngineResult``s
+        carry no ``observations``/``stream_block``: the telemetry was
+        consumed inside the program (the ring holds the bounded history).
+
+        The three host phases are wrapped in ``repro.obs.trace`` spans
+        (``closed_loop.pack`` / ``.dispatch`` / ``.epilogue``) so profiler
+        traces and span logs separate packing and adoption cost from the
+        blocking dispatch (which includes compilation on a cold cache).
+        With ``metrics=True`` the MetricFrame rides the scan carry and the
+        merged run frame is returned on ``AdaptiveResult.metrics``.
+        """
+        from .closed_loop import run_closed_loop
+
+        packed = self._pack_device_loop(arrivals, segments, metrics=metrics,
+                                        record=record)
+        m, R = len(self.servers), int(packed.carry.req_type.shape[0])
+        t0s, fc = packed.t0s, self.fleet
         with obs_trace.span("closed_loop.dispatch", segments=segments, m=m,
-                            s_cap=S_cap):
-            final, ys = run_closed_loop(
-                cluster, dyn_stack, Lp_t, logb_priors, carry0, xs, config)
+                            s_cap=int(packed.xs.seg_valid.shape[0])):
+            final, ys = run_closed_loop(*packed[:7])
             ys = jax.tree_util.tree_map(np.asarray, ys)
 
         # failures surface before any state is adopted, leaving the host

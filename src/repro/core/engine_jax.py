@@ -507,22 +507,32 @@ def _trace_segment(
         st = st._replace(slot_rem=rem)
         if telemetry:
             # integrate each running workload's co-resident counts, TDP
-            # exposure, and log instantaneous rate over [now, now + dt);
-            # inactive slots scatter to index n and are dropped. The log-rate
-            # integral is what a fleet gets from sampling its throughput
-            # counters: time-averaging log(rate) keeps the estimator's
-            # log-linear model exact across within-run co-residency changes
-            # (a plain bytes/duration rate mixes regimes arithmetically).
-            idx = jnp.where(active, st.slot_arr, n).reshape(-1)  # [m K]
-            own = jax.nn.one_hot(jnp.clip(st.slot_type, 0), T, dtype=st.counts.dtype)
-            co = jnp.maximum(st.counts[:, None, :] - own, 0.0)  # [m, K, T]
-            overflow = st.comp > dyn.tol_budget  # [m]
-            logr = jnp.log(jnp.where(active, rates, 1.0))
+            # exposure, and log instantaneous rate over [now, now + dt). The
+            # log-rate integral is what a fleet gets from sampling its
+            # throughput counters: time-averaging log(rate) keeps the
+            # estimator's log-linear model exact across within-run
+            # co-residency changes (a plain bytes/duration rate mixes regimes
+            # arithmetically). Per arrival, not per slot: a running arrival
+            # is placed (on this shard) and not finished, and gathering its
+            # server's row costs O(n T) where scattering every slot's row
+            # cost O(m K T) -- seconds per segment at 1,024 servers on a TPU.
+            srv = st.placement - lo if sharded else st.placement
+            run = ((st.placement >= 0) & jnp.isinf(st.finish_time)
+                   & (srv >= 0) & (srv < m))  # [n]
+            s = jnp.clip(srv, 0, m - 1)
+            t = jnp.clip(arr_type, 0, T - 1)
+            co = jnp.maximum(
+                st.counts[s] - jax.nn.one_hot(t, T, dtype=st.counts.dtype), 0.0)
+            over = (st.comp > dyn.tol_budget)[s]  # [n] physical TDP
+            # the arrival's rate, exactly as _slot_rates computes its slot's
+            colog = jnp.where(over, st.colog_lost[s, t], st.colog_keep[s, t])
+            ldiag = jnp.where(over, ldiag_lost[s, t], ldiag_keep[s, t])
+            base = jnp.where(over, dyn.base_lost[s, t], dyn.solo[s, t])
+            logr = jnp.log(base * jnp.exp(colog - ldiag))
             st = st._replace(
-                obs_co=st.obs_co.at[idx].add(dt * co.reshape(-1, T)),
-                obs_lost=st.obs_lost.at[idx].add(
-                    dt * jnp.broadcast_to(overflow[:, None], (m, K)).reshape(-1)),
-                obs_logr=st.obs_logr.at[idx].add(dt * logr.reshape(-1)),
+                obs_co=jnp.where(run[:, None], st.obs_co + dt * co, st.obs_co),
+                obs_lost=jnp.where(run, st.obs_lost + dt * over, st.obs_lost),
+                obs_logr=jnp.where(run, st.obs_logr + dt * logr, st.obs_logr),
             )
         return st
 

@@ -39,21 +39,11 @@ import numpy as np
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec
 
-try:  # jax >= 0.6 exports it at top level
-    _shard_map = jax.shard_map  # type: ignore[attr-defined]
-except AttributeError:  # 0.4.x: the experimental module, check_rep kwarg
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-
 def _wrap_shard_map(fn: Callable, mesh: Mesh, in_specs, out_specs) -> Callable:
-    """Version-portable shard_map. Replication checking is off: the scheduler
-    bodies return post-``pmin`` values the checker cannot prove replicated."""
-    try:
-        return _shard_map(fn, mesh, in_specs=in_specs, out_specs=out_specs,
-                          check_rep=False)
-    except TypeError:  # newer API: mesh keyword-only, check_vma instead
-        return _shard_map(fn, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_vma=False)
+    """shard_map with replication checking off: the scheduler bodies return
+    post-``pmin`` values the checker cannot prove replicated."""
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 @dataclasses.dataclass(frozen=True)

@@ -1,9 +1,10 @@
-"""Jit'd public wrappers for the Pallas kernels (the ``ops.py`` layer).
+"""Jit'd public wrappers for the model-zoo Pallas kernels (the ``ops.py``
+layer). The consolidation kernels are called directly by their users.
 
 These adapt model-layer tensor layouts to kernel layouts (GQA expansion,
 head flattening) and select the execution mode: 'tpu' (real Mosaic lowering),
-'interpret' (kernel body executed in Python on CPU -- how this container
-validates correctness), or 'jnp' (the pure-jnp reference path the production
+'interpret' (kernel body executed in Python on CPU -- how the CPU tests
+validate correctness), or 'jnp' (the pure-jnp reference path the production
 models default to off-TPU).
 """
 from __future__ import annotations
@@ -14,11 +15,9 @@ import jax
 import jax.numpy as jnp
 
 from . import ref
-from .consolidation import consolidation_scores
 from .flash_attention import flash_attention
 from .mamba_scan import mamba_scan
 from .rwkv6_scan import rwkv6_scan
-from .telemetry import pair_scatter
 
 
 def _mode_kwargs(mode: str) -> dict:
@@ -81,17 +80,3 @@ def mamba_ssm_scan(
     *, chunk: int = 64, eblock: int = 512, mode: str = "interpret",
 ) -> tuple[jax.Array, jax.Array]:
     return mamba_scan(da, dbu, c, h0, chunk=chunk, eblock=eblock, **_mode_kwargs(mode))
-
-
-def greedy_scores(
-    counts, D, rs, fs_resident, llc_budget, wtypes, *, mode: str = "interpret"
-):
-    return consolidation_scores(
-        counts, D, rs, fs_resident, llc_budget, wtypes, **_mode_kwargs(mode)
-    )
-
-
-def telemetry_pair_scatter(types, cbar, vals, *, mode: str = "interpret"):
-    """Pair-statistic scatter; ``vals`` [B] or [K, B] (K stacked statistics
-    accumulated in one batch stream -- see ``kernels.telemetry``)."""
-    return pair_scatter(types, cbar, vals, **_mode_kwargs(mode))

@@ -67,16 +67,20 @@ def _pair_scatter_kernel(types_ref, cbar_ref, vals_ref, pair_ref, base_ref):
         pair_ref[...] = jnp.zeros_like(pair_ref)
         base_ref[...] = jnp.zeros_like(base_ref)
 
+    # full f32 contractions: Mosaic's default f32 dot is not (it missed the
+    # float64 reference by 0.028 at B = 256, T = 230 on a v5e)
+    hi = jax.lax.Precision.HIGHEST
     # base[k, t] += sum_b vals[b, k] 1{t_b = t}: one [K, Bb] x [Bb, T] MXU pass
     base_ref[...] += jax.lax.dot_general(
-        vals, onehot, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        vals, onehot, (((0,), (0,)), ((), ())), precision=hi,
+        preferred_element_type=jnp.float32)
     # cbar^T @ (onehot * v_k): contract the batch axis on the MXU per statistic
     # -> K [T, T] column scatters sharing one selector build (K is static and
     # small -- 1 or 2 in the estimator -- so the unrolled loop costs nothing)
     for k in range(K):
         pair_ref[k] += jax.lax.dot_general(
             cbar, onehot * vals[:, k][:, None], (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+            precision=hi, preferred_element_type=jnp.float32)
 
 
 def pair_scatter(

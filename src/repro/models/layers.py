@@ -21,30 +21,12 @@ from jax.sharding import NamedSharding, PartitionSpec
 from .params import ParamInfo
 
 
-# resolved once at import: which shard_map the installed jax ships and
-# whether it speaks the current (axis_names/check_vma) signature
-_SM = getattr(jax, "shard_map", None)
-if _SM is None:
-    from jax.experimental.shard_map import shard_map as _SM
-import inspect as _inspect
-
-_SM_CURRENT_API = "check_vma" in _inspect.signature(_SM).parameters
-
-
 def _shard_map(body, *, mesh, in_specs, out_specs, axis_names):
-    """``jax.shard_map`` across jax versions.
-
-    ``jax.shard_map`` with ``axis_names``/``check_vma`` is a recent API; older
-    releases ship ``jax.experimental.shard_map.shard_map`` where the manual
-    axis set is expressed through its complement (``auto``) and replication
-    checking through ``check_rep``.
-    """
-    if _SM_CURRENT_API:
-        return _SM(body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                   axis_names=axis_names, check_vma=False)
-    auto = frozenset(mesh.axis_names) - frozenset(axis_names)
-    return _SM(body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False, auto=auto)
+    """``jax.shard_map`` manual over ``axis_names`` (auto over the rest),
+    with replication checking off."""
+    return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, axis_names=axis_names,
+                         check_vma=False)
 
 # --- activation-sharding context ------------------------------------------------
 

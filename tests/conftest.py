@@ -4,13 +4,5 @@ import os
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 import jax
-import pytest
 
 jax.config.update("jax_enable_x64", False)
-
-# partial-auto shard_map (manual over a subset of mesh axes) lowers to a
-# PartitionId op that older jaxlibs' SPMD partitioner rejects; the native
-# jax.shard_map releases handle it. The multidev probes exercise that path.
-requires_native_shard_map = pytest.mark.skipif(
-    not hasattr(jax, "shard_map"),
-    reason="partial-auto shard_map needs a jax release with native jax.shard_map")

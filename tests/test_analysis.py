@@ -42,26 +42,26 @@ from repro.analysis.retrace import CompileCacheGuard, run_retrace_audit
 
 GOLDEN_PRIMITIVES = {
     "kernels.consolidation.consolidation_scores": {
-        "add": 4, "broadcast_in_dim": 7, "concatenate": 2,
-        "convert_element_type": 6, "div": 1, "dot_general": 1, "eq": 1,
-        "gather": 1, "get": 7, "gt": 1, "iota": 3, "lt": 2, "max": 1,
-        "min": 1, "mul": 2, "pallas_call": 1, "pjit": 4, "reduce_max": 1,
-        "reduce_sum": 2, "reshape": 2, "select_n": 3, "slice": 1,
-        "squeeze": 1, "sub": 1, "swap": 2,
+        "add": 4, "broadcast_in_dim": 9, "concatenate": 2,
+        "convert_element_type": 5, "div": 1, "dot_general": 1, "eq": 1,
+        "gather": 1, "get": 7, "gt": 1, "iota": 3, "jit": 5, "lt": 2,
+        "max": 1, "min": 1, "mul": 2, "pad": 1, "pallas_call": 1,
+        "reduce_max": 1, "reduce_sum": 2, "reshape": 4, "select_n": 3,
+        "slice": 2, "squeeze": 2, "sub": 1, "swap": 2, "transpose": 2,
     },
     "kernels.telemetry.pair_scatter": {
         "add": 3, "broadcast_in_dim": 5, "cond": 1, "convert_element_type": 2,
-        "dot_general": 3, "eq": 2, "get": 6, "iota": 1, "mul": 2,
-        "pallas_call": 1, "pjit": 1, "program_id": 1, "reshape": 1,
-        "slice": 2, "squeeze": 2, "swap": 5, "transpose": 1,
+        "dot_general": 3, "eq": 2, "get": 6, "iota": 1, "jit": 1, "mul": 2,
+        "pallas_call": 1, "program_id": 1, "reshape": 1, "slice": 2,
+        "squeeze": 2, "swap": 5, "transpose": 1,
     },
     "engine.make_scorer[pallas]": {
-        "add": 4, "broadcast_in_dim": 8, "concatenate": 2,
-        "convert_element_type": 6, "div": 1, "dot_general": 1, "eq": 1,
-        "gather": 1, "get": 7, "gt": 1, "iota": 3, "lt": 2, "max": 1,
-        "min": 1, "mul": 3, "pallas_call": 1, "pjit": 5, "reduce_max": 1,
-        "reduce_sum": 2, "reshape": 2, "select_n": 3, "slice": 1,
-        "squeeze": 1, "sub": 1, "swap": 2,
+        "add": 4, "broadcast_in_dim": 10, "concatenate": 2,
+        "convert_element_type": 5, "div": 1, "dot_general": 1, "eq": 1,
+        "gather": 1, "get": 7, "gt": 1, "iota": 3, "jit": 6, "lt": 2,
+        "max": 1, "min": 1, "mul": 3, "pad": 1, "pallas_call": 1,
+        "reduce_max": 1, "reduce_sum": 2, "reshape": 4, "select_n": 3,
+        "slice": 2, "squeeze": 2, "sub": 1, "swap": 2, "transpose": 2,
     },
 }
 
@@ -103,7 +103,7 @@ def test_device_tier_rejects_callback():
     from repro.analysis.jaxpr_audit import HotEntry, _check_eqns
 
     def leaky(x):
-        jax.debug.print("x = {}", x)  # lowers to debug_callback
+        jax.debug.print("x = {}", x)  # lowers to debug_print
         return x * 2.0
 
     entry = HotEntry("test.leaky", TIER_DEVICE,

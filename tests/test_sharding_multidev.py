@@ -8,7 +8,6 @@ import sys
 import textwrap
 
 import pytest
-from conftest import requires_native_shard_map
 
 PROBE = textwrap.dedent(
     """
@@ -60,7 +59,6 @@ PROBE = textwrap.dedent(
 
 
 @pytest.mark.slow
-@requires_native_shard_map
 def test_sharded_loss_matches_single_device():
     env = dict(os.environ)
     env["PYTHONPATH"] = env.get("PYTHONPATH", "") + os.pathsep + os.path.abspath(

@@ -8,7 +8,6 @@ import sys
 import textwrap
 
 import pytest
-from conftest import requires_native_shard_map
 
 PROBE = textwrap.dedent(
     """
@@ -81,7 +80,6 @@ PROBE = textwrap.dedent(
 
 
 @pytest.mark.slow
-@requires_native_shard_map
 def test_perf_variants_numerically_correct_on_mesh():
     env = dict(os.environ)
     env["PYTHONPATH"] = env.get("PYTHONPATH", "") + os.pathsep + os.path.abspath(
