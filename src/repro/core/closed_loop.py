@@ -328,45 +328,48 @@ def run_closed_loop(
                 # original (un-remapped) block -- FleetController.observe's
                 # exact order; each shard folds its own servers' rows
                 # (pool locality keeps row_map shard-local)
-                if sharded:
-                    det_row_map = (jax.lax.dynamic_slice_in_dim(
-                        carry.row_map, lo, m_l) - lo)
-                    det_block = _localize_block(block, lo)
-                else:
-                    det_row_map, det_block = carry.row_map, block
-                det, _ = _cusum_update(
-                    carry.det, det_block, bank.log_b, bank.L_t, det_row_map,
-                    k=config.cusum_k, level_decay=config.level_decay,
-                    max_lost_frac=config.det_max_lost_frac)
-                # burn-in: discard detector evidence, withhold actions
-                in_warmup = seen <= config.warmup_segments
-                det = jax.tree_util.tree_map(
-                    lambda a: jnp.where(in_warmup, jnp.zeros_like(a), a), det)
-                out = fleet_step(
-                    bank, det, carry.row_map, carry.read_row, carry.active,
-                    logb_priors, x.seg_valid & ~in_warmup,
-                    h=config.cusum_h, level_decay=config.level_decay,
-                    fail_floor=config.fail_floor,
-                    min_exposure=config.min_exposure, axis=axis)
-                bank, det = out.bank, out.det
+                with jax.named_scope("obs.detect"):
+                    if sharded:
+                        det_row_map = (jax.lax.dynamic_slice_in_dim(
+                            carry.row_map, lo, m_l) - lo)
+                        det_block = _localize_block(block, lo)
+                    else:
+                        det_row_map, det_block = carry.row_map, block
+                    det, _ = _cusum_update(
+                        carry.det, det_block, bank.log_b, bank.L_t, det_row_map,
+                        k=config.cusum_k, level_decay=config.level_decay,
+                        max_lost_frac=config.det_max_lost_frac)
+                    # burn-in: discard detector evidence, withhold actions
+                    in_warmup = seen <= config.warmup_segments
+                    det = jax.tree_util.tree_map(
+                        lambda a: jnp.where(in_warmup, jnp.zeros_like(a), a), det)
+                    out = fleet_step(
+                        bank, det, carry.row_map, carry.read_row, carry.active,
+                        logb_priors, x.seg_valid & ~in_warmup,
+                        h=config.cusum_h, level_decay=config.level_decay,
+                        fail_floor=config.fail_floor,
+                        min_exposure=config.min_exposure, axis=axis)
+                    bank, det = out.bank, out.det
                 row_map, read_row, active = out.row_map, out.read_row, out.active
                 split_fired, split_stat = out.split_fired, out.split_stat
                 evict_fired, evict_stat = out.evict_fired, out.evict_stat
                 evict_route = out.evict_route
                 # topology changes remap reads/copy rows: rebuild D outright;
                 # otherwise refresh just this segment's touched columns
-                D = jax.lax.cond(
-                    jnp.any(split_fired) | jnp.any(evict_fired),
-                    lambda d: full_D(bank, read_row),
-                    lambda d: refresh_D(d, bank, read_row, a_type, rblock),
-                    D)
+                with jax.named_scope("obs.d_refresh"):
+                    D = jax.lax.cond(
+                        jnp.any(split_fired) | jnp.any(evict_fired),
+                        lambda d: full_D(bank, read_row),
+                        lambda d: refresh_D(d, bank, read_row, a_type, rblock),
+                        D)
             else:
                 det = carry.det
                 row_map, read_row, active = (
                     carry.row_map, carry.read_row, carry.active)
                 split_fired = evict_fired = evict_route = jnp.zeros((m,), bool)
                 split_stat = evict_stat = jnp.zeros((m,), jnp.float32)
-                D = refresh_D(D, bank, read_row, a_type, rblock)
+                with jax.named_scope("obs.d_refresh"):
+                    D = refresh_D(D, bank, read_row, a_type, rblock)
 
             # act -> re-schedule: work an evicted server held (or that never
             # placed) re-enters at the head of the next segment, in row order

@@ -843,108 +843,111 @@ class AdaptiveEngine:
                              "confidence_floor; estimators disagree")
 
         with obs_trace.span("closed_loop.pack", segments=segments, m=m):
-            ordered = sorted(arrivals, key=lambda tw: tw[0])
-            times = np.asarray([t for t, _ in ordered], np.float64)
-            wtypes = np.asarray([type_index(w) for _, w in ordered], np.int32)
-            nbytes = np.asarray([w.data_total for _, w in ordered], np.float64)
+            with obs_trace.span("closed_loop.pack.arrivals"):
+                ordered = sorted(arrivals, key=lambda tw: tw[0])
+                times = np.asarray([t for t, _ in ordered], np.float64)
+                wtypes = np.asarray([type_index(w) for _, w in ordered], np.int32)
+                nbytes = np.asarray([w.data_total for _, w in ordered], np.float64)
 
-            # segments bucket to a power-of-two count (padding masked by
-            # seg_valid) so warm runs across different segment counts of the
-            # same fleet hit one compilation
-            S_cap = 4
-            while S_cap < segments:
-                S_cap *= 2
-            arr_time = np.zeros((S_cap, n_seg), np.float32)
-            arr_type = np.zeros((S_cap, n_seg), np.int32)
-            arr_bytes = np.ones((S_cap, n_seg), np.float32)
-            t0s = []
-            for k in range(segments):
-                sl = slice(k * n_seg, (k + 1) * n_seg)
-                t0 = float(times[k * n_seg])
-                t0s.append(t0)
-                arr_time[k] = times[sl] - t0
-                arr_type[k] = wtypes[sl]
-                arr_bytes[k] = nbytes[sl]
+                # segments bucket to a power-of-two count (padding masked by
+                # seg_valid) so warm runs across different segment counts of the
+                # same fleet hit one compilation
+                S_cap = 4
+                while S_cap < segments:
+                    S_cap *= 2
+                arr_time = np.zeros((S_cap, n_seg), np.float32)
+                arr_type = np.zeros((S_cap, n_seg), np.int32)
+                arr_bytes = np.ones((S_cap, n_seg), np.float32)
+                t0s = []
+                for k in range(segments):
+                    sl = slice(k * n_seg, (k + 1) * n_seg)
+                    t0 = float(times[k * n_seg])
+                    t0s.append(t0)
+                    arr_time[k] = times[sl] - t0
+                    arr_type[k] = wtypes[sl]
+                    arr_bytes[k] = nbytes[sl]
 
-            # per-segment worlds, deduplicated into one stacked dynamics bank;
-            # the compiled cluster's structural tables must hold for all of them
-            structural = [(s.llc_bytes, s.llc_tolerance) for s in self.servers]
-            spec_of: dict[tuple[ServerSpec, ...], int] = {}
-            dyn_idx = np.zeros(S_cap, np.int32)
-            for k in range(segments):
-                specs = (tuple(self.drift.specs_at(self.servers, k))
-                         if self.drift is not None else self.servers)
-                if [(s.llc_bytes, s.llc_tolerance) for s in specs] != structural:
-                    raise ValueError(
-                        "device_loop=True compiles one cluster for all segments: "
-                        "drift may not change llc_bytes/llc_tolerance (run the "
-                        "host-alternating path for structural drift)")
-                dyn_idx[k] = spec_of.setdefault(specs, len(spec_of))
-            for specs in spec_of:
-                if specs not in self._dyn_cache:
-                    self._dyn_cache[specs] = PackedDynamics.build(list(specs))
-            dyn_stack = jax.tree_util.tree_map(
-                lambda *a: jnp.stack(a), *(self._dyn_cache[s] for s in spec_of))
-            cluster = PackedCluster.build(
-                list(self.servers),
-                [np.zeros((GRID_T, GRID_T), np.float32)] * m, self.alpha)
+            with obs_trace.span("closed_loop.pack.tables"):
+                # per-segment worlds, deduplicated into one stacked dynamics bank;
+                # the compiled cluster's structural tables must hold for all of them
+                structural = [(s.llc_bytes, s.llc_tolerance) for s in self.servers]
+                spec_of: dict[tuple[ServerSpec, ...], int] = {}
+                dyn_idx = np.zeros(S_cap, np.int32)
+                for k in range(segments):
+                    specs = (tuple(self.drift.specs_at(self.servers, k))
+                             if self.drift is not None else self.servers)
+                    if [(s.llc_bytes, s.llc_tolerance) for s in specs] != structural:
+                        raise ValueError(
+                            "device_loop=True compiles one cluster for all segments: "
+                            "drift may not change llc_bytes/llc_tolerance (run the "
+                            "host-alternating path for structural drift)")
+                    dyn_idx[k] = spec_of.setdefault(specs, len(spec_of))
+                for specs in spec_of:
+                    if specs not in self._dyn_cache:
+                        self._dyn_cache[specs] = PackedDynamics.build(list(specs))
+                dyn_stack = jax.tree_util.tree_map(
+                    lambda *a: jnp.stack(a), *(self._dyn_cache[s] for s in spec_of))
+                cluster = PackedCluster.build(
+                    list(self.servers),
+                    [np.zeros((GRID_T, GRID_T), np.float32)] * m, self.alpha)
 
-            Lp_t = jnp.asarray(
-                np.stack([e._L_prior.T for e in self.estimators]), jnp.float32)
-            logb_priors = jnp.asarray(
-                np.stack([e._logb_prior for e in self.estimators]), jnp.float32)
+                Lp_t = jnp.asarray(
+                    np.stack([e._L_prior.T for e in self.estimators]), jnp.float32)
+                logb_priors = jnp.asarray(
+                    np.stack([e._logb_prior for e in self.estimators]), jnp.float32)
 
-            scorer = None if self.scorer == "jnp" else make_scorer(self.scorer)
-            h = e0._hypers
-            est_h = dict(
-                lr=h["lr"], decay=h["decay"], step_damp=h["step_damp"],
-                solo_eps=h["solo_eps"], est_max_lost_frac=h["max_lost_frac"],
-                use_pallas=h["use_pallas"])
-            frame0 = obs_metrics.zeros(m) if metrics else None
-            rec0 = self._decision_ring().state if record else None
-            fc = self.fleet
-            if fc is not None:
-                fc._require_bound()
-                config = ClosedLoopConfig(
-                    objective=self.objective, scorer=scorer, fleet=True,
-                    warmup_segments=fc.warmup_segments, cusum_k=fc.cusum_k,
-                    cusum_h=fc.cusum_h, level_decay=fc.level_decay,
-                    fail_floor=fc.fail_floor, min_exposure=fc.min_exposure,
-                    det_max_lost_frac=fc.max_lost_frac,
-                    confidence_floor=float(e0.confidence_floor),
-                    metrics=metrics, record=record, **est_h)
-                carry0 = LoopCarry(
-                    bank=fc.pool.bank.stacked_state(), det=fc.detector.state,
-                    row_map=jnp.asarray(fc.pool.row_of, jnp.int32),
-                    read_row=jnp.asarray(fc.pool._read_row, jnp.int32),
-                    active=jnp.asarray(fc._active),
-                    seen=jnp.int32(fc._segments_seen),
-                    req_type=jnp.zeros((R,), jnp.int32),
-                    req_bytes=jnp.ones((R,), jnp.float32),
-                    req_n=jnp.int32(0),
-                    ring=self.ring._buf, ring_ptr=jnp.int32(self.ring.ptr),
-                    ring_total=jnp.int32(self.ring.total),
-                    metrics=frame0, rec=rec0)
-            else:
-                config = ClosedLoopConfig(
-                    objective=self.objective, scorer=scorer, fleet=False,
-                    confidence_floor=float(e0.confidence_floor),
-                    metrics=metrics, record=record, **est_h)
-                carry0 = LoopCarry(
-                    bank=self.bank.stacked_state(), det=CusumState.zeros(m),
-                    row_map=jnp.arange(m, dtype=jnp.int32),
-                    read_row=jnp.arange(m, dtype=jnp.int32),
-                    active=jnp.ones(m, bool), seen=jnp.int32(0),
-                    req_type=jnp.zeros((R,), jnp.int32),
-                    req_bytes=jnp.ones((R,), jnp.float32),
-                    req_n=jnp.int32(0),
-                    ring=self.ring._buf, ring_ptr=jnp.int32(self.ring.ptr),
-                    ring_total=jnp.int32(self.ring.total),
-                    metrics=frame0, rec=rec0)
-            xs = SegmentIn(
-                arr_time=jnp.asarray(arr_time), arr_type=jnp.asarray(arr_type),
-                arr_bytes=jnp.asarray(arr_bytes), dyn_idx=jnp.asarray(dyn_idx),
-                seg_valid=jnp.asarray(np.arange(S_cap) < segments))
+            with obs_trace.span("closed_loop.pack.state"):
+                scorer = None if self.scorer == "jnp" else make_scorer(self.scorer)
+                h = e0._hypers
+                est_h = dict(
+                    lr=h["lr"], decay=h["decay"], step_damp=h["step_damp"],
+                    solo_eps=h["solo_eps"], est_max_lost_frac=h["max_lost_frac"],
+                    use_pallas=h["use_pallas"])
+                frame0 = obs_metrics.zeros(m) if metrics else None
+                rec0 = self._decision_ring().state if record else None
+                fc = self.fleet
+                if fc is not None:
+                    fc._require_bound()
+                    config = ClosedLoopConfig(
+                        objective=self.objective, scorer=scorer, fleet=True,
+                        warmup_segments=fc.warmup_segments, cusum_k=fc.cusum_k,
+                        cusum_h=fc.cusum_h, level_decay=fc.level_decay,
+                        fail_floor=fc.fail_floor, min_exposure=fc.min_exposure,
+                        det_max_lost_frac=fc.max_lost_frac,
+                        confidence_floor=float(e0.confidence_floor),
+                        metrics=metrics, record=record, **est_h)
+                    carry0 = LoopCarry(
+                        bank=fc.pool.bank.stacked_state(), det=fc.detector.state,
+                        row_map=jnp.asarray(fc.pool.row_of, jnp.int32),
+                        read_row=jnp.asarray(fc.pool._read_row, jnp.int32),
+                        active=jnp.asarray(fc._active),
+                        seen=jnp.int32(fc._segments_seen),
+                        req_type=jnp.zeros((R,), jnp.int32),
+                        req_bytes=jnp.ones((R,), jnp.float32),
+                        req_n=jnp.int32(0),
+                        ring=self.ring._buf, ring_ptr=jnp.int32(self.ring.ptr),
+                        ring_total=jnp.int32(self.ring.total),
+                        metrics=frame0, rec=rec0)
+                else:
+                    config = ClosedLoopConfig(
+                        objective=self.objective, scorer=scorer, fleet=False,
+                        confidence_floor=float(e0.confidence_floor),
+                        metrics=metrics, record=record, **est_h)
+                    carry0 = LoopCarry(
+                        bank=self.bank.stacked_state(), det=CusumState.zeros(m),
+                        row_map=jnp.arange(m, dtype=jnp.int32),
+                        read_row=jnp.arange(m, dtype=jnp.int32),
+                        active=jnp.ones(m, bool), seen=jnp.int32(0),
+                        req_type=jnp.zeros((R,), jnp.int32),
+                        req_bytes=jnp.ones((R,), jnp.float32),
+                        req_n=jnp.int32(0),
+                        ring=self.ring._buf, ring_ptr=jnp.int32(self.ring.ptr),
+                        ring_total=jnp.int32(self.ring.total),
+                        metrics=frame0, rec=rec0)
+                xs = SegmentIn(
+                    arr_time=jnp.asarray(arr_time), arr_type=jnp.asarray(arr_type),
+                    arr_bytes=jnp.asarray(arr_bytes), dyn_idx=jnp.asarray(dyn_idx),
+                    seg_valid=jnp.asarray(np.arange(S_cap) < segments))
         return DeviceLoopInputs(cluster, dyn_stack, Lp_t, logb_priors, carry0,
                                 xs, config, tuple(t0s))
 
@@ -976,8 +979,12 @@ class AdaptiveEngine:
         t0s, fc = packed.t0s, self.fleet
         with obs_trace.span("closed_loop.dispatch", segments=segments, m=m,
                             s_cap=int(packed.xs.seg_valid.shape[0])):
-            final, ys = run_closed_loop(*packed[:7])
-            ys = jax.tree_util.tree_map(np.asarray, ys)
+            with obs_trace.span("closed_loop.dispatch.call"):
+                final, ys = run_closed_loop(*packed[:7])
+            with obs_trace.span("closed_loop.dispatch.wait"):
+                jax.block_until_ready(ys)
+            with obs_trace.span("closed_loop.dispatch.fetch"):
+                ys = jax.tree_util.tree_map(np.asarray, ys)
 
         # failures surface before any state is adopted, leaving the host
         # objects where they were (the failed run never happened)
@@ -1033,10 +1040,6 @@ class AdaptiveEngine:
             self.ring.total = int(final.ring_total)
             if record:
                 self.decisions.adopt(final.rec)
-            log = obs_trace.active_log()
-            if metrics and log is not None:
-                log.snapshot("closed_loop.metrics",
-                             obs_metrics.snapshot(final.metrics))
         return AdaptiveResult(tuple(results), tuple(n_obs), tuple(t0s),
                               tuple(health), metrics=final.metrics,
                               decisions=self.decisions if record else None)

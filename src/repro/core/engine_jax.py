@@ -309,6 +309,7 @@ def _trace_segment(
         maxd = jnp.where(jnp.any(present, axis=1), maxd, 0.0)
         return cache, maxd
 
+    @jax.named_scope("obs.score")
     def greedy_pick(st, wtypes):
         """Scoring + Fig-8 argmin (Table II / Fig-8 objective) for a batch."""
         wtypes = jnp.atleast_1d(wtypes)
@@ -538,6 +539,7 @@ def _trace_segment(
 
     W = min(8, n)  # drain fast-path window (first W queued candidates)
 
+    @jax.named_scope("obs.drain")
     def drain_branch(st, rates, tt):
         del rates, tt
         # Queue order == arrival order (workloads are never re-queued), so the
@@ -591,6 +593,7 @@ def _trace_segment(
             st = st._replace(metrics=mf)
         return st._replace(draining=found, deadlock=st.deadlock | dead)
 
+    @jax.named_scope("obs.finish")
     def finish_branch(st, rates, tt):
         # margin argmin: exactly-simultaneous completions (identical workloads
         # on same-spec servers) must resolve lowest-server-first like the
@@ -656,6 +659,7 @@ def _trace_segment(
             draining=jnp.any(st.queued),  # §V: completion may unblock the queue
         )
 
+    @jax.named_scope("obs.arrive")
     def arrive_branch(st, rates, tt):
         del tt
         t_arr = arr_time[st.ai]
@@ -672,7 +676,10 @@ def _trace_segment(
         return st.deadlock | (
             (st.ai >= n_valid) & ~jnp.any(st.slot_type >= 0) & ~jnp.any(st.queued))
 
-    def event_step(st):
+    @jax.named_scope("obs.rates")
+    def pick_event(st):
+        """Slot rates, observed degradation and finish times of the running
+        set, and which micro-event comes next (0 drain, 1 finish, 2 arrive)."""
         overflow = st.comp > dyn.tol_budget
         rates = _slot_rates(dyn, ldiag_keep, ldiag_lost, overflow,
                             st.colog_keep, st.colog_lost, st.slot_type)
@@ -712,6 +719,10 @@ def _trace_segment(
         queue_any = jnp.any(st.queued)
         drain = st.draining | (queue_any & ~any_active & (st.ai >= n_valid))
         branch = jnp.where(drain, 0, jnp.where(any_active & (t_fin <= t_arr), 1, 2))
+        return st, rates, tt, branch
+
+    def event_step(st):
+        st, rates, tt, branch = pick_event(st)
         return jax.lax.switch(
             branch, [drain_branch, finish_branch, arrive_branch], st, rates, tt)
 

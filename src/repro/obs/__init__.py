@@ -10,10 +10,10 @@ Three layers, hot to cold:
               the engines; off means the carried slot is ``None`` (an empty
               pytree) and the compiled program is byte-identical.
 ``trace``     host-side structured spans around the phases that *surround*
-              the device programs (pack/dispatch/epilogue), emitted both as
-              ``jax.profiler`` annotations (so ``--profile`` traces are
-              navigable) and as an optional JSONL span+snapshot log stamped
-              with the git commit.
+              the device programs (pack/dispatch/epilogue and their
+              sub-phases), emitted both as ``jax.profiler`` annotations (so
+              ``--profile`` traces are navigable) and, with tracing on, as
+              an in-memory span log on the profiler's host clock.
 ``report``    renders a run report (counter/gauge/percentile tables,
               per-server utilization-floor violations, fleet health-event
               timeline) from an ``EngineResult``/``AdaptiveResult``, and

@@ -1,97 +1,66 @@
 """Host-side structured spans around the device-resident programs.
 
-The hot path itself is one XLA program -- there is nothing host-visible to
-time inside it, by design (DESIGN.md §12). What *is* host-visible, and what
-dominates interactive latency, are the phases around it: packing segment
-buffers, the blocking dispatch (compile on a cold cache, execute on a warm
-one), and the epilogue that adopts device outcomes back into host
-bookkeeping. :func:`span` wraps those phases with
+The hot path itself is one XLA program; its layers are told apart on the
+device by ``jax.named_scope`` (``obs.*`` op-name prefixes). What is
+host-visible, and what dominates interactive latency, are the phases around
+it: packing segment buffers, the blocking dispatch (compile on a cold cache,
+execute on a warm one), and the epilogue that adopts device outcomes back
+into host bookkeeping. :func:`span` wraps those phases with
 
-  * ``jax.profiler.TraceAnnotation`` -- so ``--profile`` traces from the
-    benchmark harness are navigable by phase name, and
-  * an optional JSONL log (:class:`SpanLog`) of ``{"kind": "span", ...}``
-    rows stamped with wall-clock times and the git commit, plus
-    ``{"kind": "snapshot", ...}`` rows for MetricFrame snapshots.
+  * ``jax.profiler.TraceAnnotation`` -- so profiler traces are navigable by
+    phase name, and
+  * an in-memory :class:`SpanLog` when tracing is enabled.
+
+Each logged span is stamped on ``time.time_ns()``, the clock the profiler
+stamps its host events with, so a span and its annotation in an
+``.xplane.pb`` mark the same interval and spans outside a profiler slice can
+still be laid on the slice's timeline.
 
 Tracing is off by default; :func:`span` then degrades to a bare profiler
-annotation (nanoseconds when no profiler is attached). Enable with
-``enable_tracing(path)``; rows append eagerly so a crashed run keeps its
-prefix.
+annotation (nanoseconds when no profiler is attached).
 """
 from __future__ import annotations
 
 import contextlib
 import dataclasses
-import json
-import pathlib
-import subprocess
 import time
 
 import jax
 
 
-def _git_commit() -> str:
-    try:
-        root = pathlib.Path(__file__).resolve().parents[3]
-        out = subprocess.run(
-            ["git", "rev-parse", "--short", "HEAD"], cwd=root,
-            capture_output=True, text=True, timeout=5)
-        if out.returncode == 0:
-            return out.stdout.strip()
-    except (OSError, subprocess.SubprocessError):
-        pass
-    return "unknown"
-
-
-_COMMIT: "str | None" = None
-
-
-def commit_stamp() -> str:
-    global _COMMIT
-    if _COMMIT is None:
-        _COMMIT = _git_commit()
-    return _COMMIT
-
-
 @dataclasses.dataclass
 class Span:
     name: str
-    t_start: float
-    duration_s: float
+    start_ns: int  # time.time_ns() at open
+    end_ns: int  # time.time_ns() at close
     attrs: dict
     #: stable per-log id, assigned at span *open* so parents number before
     #: their children even though children close (and append) first
     id: int = 0
     #: id of the enclosing open span, None for top-level phases
     parent: "int | None" = None
-    #: nesting depth (0 = top level); redundant with the parent chain but
-    #: kept on the row so JSONL consumers can indent without a join
+    #: nesting depth (0 = top level)
     depth: int = 0
+
+    @property
+    def duration_s(self) -> float:
+        return (self.end_ns - self.start_ns) * 1e-9
 
 
 class SpanLog:
-    """Collects spans and metric snapshots; optionally appends JSONL rows.
+    """Collects spans in memory.
 
     Nested :meth:`span` calls are linked: each span records the ``id`` of
     the span that was open when it started (``parent``) and its nesting
-    ``depth``, so a dispatch phase that packs, compiles, and adopts inside
-    an outer segment span renders as a tree rather than a flat list
+    ``depth``, so a dispatch phase's call / wait / fetch inside the outer
+    dispatch span renders as a tree rather than a flat list
     (:func:`repro.obs.report.phase_tree`).
     """
 
-    def __init__(self, path: "str | pathlib.Path | None" = None):
-        self.path = pathlib.Path(path) if path is not None else None
+    def __init__(self):
         self.spans: "list[Span]" = []
-        self._t0 = time.time()
         self._next_id = 0
         self._open: "list[int]" = []  # ids of currently open spans
-
-    def _write(self, row: dict) -> None:
-        if self.path is None:
-            return
-        row = dict(row, commit=commit_stamp())
-        with self.path.open("a") as fh:
-            fh.write(json.dumps(row) + "\n")
 
     @contextlib.contextmanager
     def span(self, name: str, **attrs):
@@ -100,24 +69,15 @@ class SpanLog:
         parent = self._open[-1] if self._open else None
         depth = len(self._open)
         self._open.append(sid)
-        t0 = time.time()
-        p0 = time.perf_counter()
         try:
             with jax.profiler.TraceAnnotation(name):
+                t0 = time.time_ns()
                 yield
+                t1 = time.time_ns()
         finally:
             self._open.pop()
-        dt = time.perf_counter() - p0
-        self.spans.append(Span(name, t0, dt, attrs, id=sid, parent=parent,
+        self.spans.append(Span(name, t0, t1, attrs, id=sid, parent=parent,
                                depth=depth))
-        self._write({"kind": "span", "name": name, "t_start": t0,
-                     "duration_s": dt, "attrs": attrs, "id": sid,
-                     "parent": parent, "depth": depth})
-
-    def snapshot(self, name: str, payload: dict) -> None:
-        """Record a point-in-time payload (e.g. ``metrics.snapshot(frame)``)."""
-        self._write({"kind": "snapshot", "name": name, "t": time.time(),
-                     "payload": payload})
 
     def durations(self) -> "dict[str, float]":
         """Total seconds per span name."""
@@ -130,10 +90,10 @@ class SpanLog:
 _ACTIVE: "SpanLog | None" = None
 
 
-def enable_tracing(path: "str | pathlib.Path | None" = None) -> SpanLog:
-    """Install a process-wide SpanLog (optionally JSONL-backed)."""
+def enable_tracing() -> SpanLog:
+    """Install a process-wide SpanLog."""
     global _ACTIVE
-    _ACTIVE = SpanLog(path)
+    _ACTIVE = SpanLog()
     return _ACTIVE
 
 
