@@ -567,6 +567,14 @@ class AdaptiveEngine:
         # specs alone and is shared by all mask variants of a world.
         self._engine_cache: dict[tuple, ConsolidationEngine] = {}
         self._dyn_cache: dict[tuple[ServerSpec, ...], PackedDynamics] = {}
+        # the device loop's loop-invariant inputs (_pack_device_loop): the
+        # cluster and stacked dynamics keyed by (per-segment worlds, alpha),
+        # the estimator priors in one slot built on first use
+        self._loop_tables: dict[tuple, tuple[PackedCluster, PackedDynamics]] = {}
+        self._loop_priors: "tuple[jax.Array, jax.Array] | None" = None
+        #: device-loop runs that found their tables built (hits) or built
+        #: them (misses): one miss per distinct (worlds, alpha)
+        self.loop_tables_stats = {"hits": 0, "misses": 0}
 
         priors: list[np.ndarray | float]
         if isinstance(prior, str):
@@ -816,7 +824,31 @@ class AdaptiveEngine:
         """The device loop's prologue (the ``closed_loop.pack`` span):
         validate the run, pack arrivals and dynamics, and snapshot the live
         estimator/detector/pool state into the scan carry -- everything
-        one ``run_closed_loop`` call consumes."""
+        one ``run_closed_loop`` call consumes.
+
+        Per engine, built on the first run that needs them and reused
+        (``closed_loop.pack.tables``, attribute ``cached``; counted in
+        ``loop_tables_stats``):
+
+        * ``cluster`` and ``dyn_stack``, keyed by the ordered tuple of
+          distinct per-segment worlds and ``alpha``. They are functions of
+          that key alone: the cluster's structural tables come from the
+          servers' LLC sizes (which every world must share) and ``alpha``,
+          its ``D``/``active`` are replaced inside the program from the
+          carry, so evictions need no new entry; the dynamics are the
+          worlds' tables. A drift schedule indexes worlds by segment, so a
+          repeated run repeats its key.
+        * ``Lp_t`` and ``logb_priors``: the estimators' priors, fixed at
+          their construction.
+
+        ``run_closed_loop`` donates none of its inputs, so a cached array
+        survives every call. At 1,024 servers the cluster's and the priors'
+        ``[m, T, T]`` tables are ~217 MB each, held for the engine's life:
+        the memory each run allocated anew before, so peak use does not
+        rise (one more cluster and dynamics stack per further key).
+
+        Per run: the arrivals, the structural-drift check and ``dyn_idx``
+        (cheap host numpy), and the carry, whose state moves every run."""
         from ..fleet.detect import CusumState
         from .closed_loop import ClosedLoopConfig, LoopCarry, SegmentIn
 
@@ -867,7 +899,7 @@ class AdaptiveEngine:
                     arr_type[k] = wtypes[sl]
                     arr_bytes[k] = nbytes[sl]
 
-            with obs_trace.span("closed_loop.pack.tables"):
+            with obs_trace.span("closed_loop.pack.tables") as tables_span:
                 # per-segment worlds, deduplicated into one stacked dynamics bank;
                 # the compiled cluster's structural tables must hold for all of them
                 structural = [(s.llc_bytes, s.llc_tolerance) for s in self.servers]
@@ -882,19 +914,34 @@ class AdaptiveEngine:
                             "drift may not change llc_bytes/llc_tolerance (run the "
                             "host-alternating path for structural drift)")
                     dyn_idx[k] = spec_of.setdefault(specs, len(spec_of))
-                for specs in spec_of:
-                    if specs not in self._dyn_cache:
-                        self._dyn_cache[specs] = PackedDynamics.build(list(specs))
-                dyn_stack = jax.tree_util.tree_map(
-                    lambda *a: jnp.stack(a), *(self._dyn_cache[s] for s in spec_of))
-                cluster = PackedCluster.build(
-                    list(self.servers),
-                    [np.zeros((GRID_T, GRID_T), np.float32)] * m, self.alpha)
+                alpha = self.alpha
+                key = (tuple(spec_of), alpha if isinstance(alpha, (int, float))
+                       else tuple(alpha))
+                tables = self._loop_tables.get(key)
+                tables_span["cached"] = tables is not None
+                if tables is None:
+                    self.loop_tables_stats["misses"] += 1
+                    for specs in spec_of:
+                        if specs not in self._dyn_cache:
+                            self._dyn_cache[specs] = PackedDynamics.build(list(specs))
+                    dyn_stack = jax.tree_util.tree_map(
+                        lambda *a: jnp.stack(a),
+                        *(self._dyn_cache[s] for s in spec_of))
+                    cluster = PackedCluster.build(
+                        list(self.servers),
+                        [np.zeros((GRID_T, GRID_T), np.float32)] * m, alpha)
+                    tables = self._loop_tables[key] = (cluster, dyn_stack)
+                else:
+                    self.loop_tables_stats["hits"] += 1
+                cluster, dyn_stack = tables
 
-                Lp_t = jnp.asarray(
-                    np.stack([e._L_prior.T for e in self.estimators]), jnp.float32)
-                logb_priors = jnp.asarray(
-                    np.stack([e._logb_prior for e in self.estimators]), jnp.float32)
+                if self._loop_priors is None:
+                    self._loop_priors = (
+                        jnp.asarray(np.stack([e._L_prior.T for e in self.estimators]),
+                                    jnp.float32),
+                        jnp.asarray(np.stack([e._logb_prior for e in self.estimators]),
+                                    jnp.float32))
+                Lp_t, logb_priors = self._loop_priors
 
             with obs_trace.span("closed_loop.pack.state"):
                 scorer = None if self.scorer == "jnp" else make_scorer(self.scorer)

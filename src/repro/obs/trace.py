@@ -64,6 +64,8 @@ class SpanLog:
 
     @contextlib.contextmanager
     def span(self, name: str, **attrs):
+        """Log one span; yields its ``attrs``, which the phase may extend
+        with what it learns inside (a cache outcome, say)."""
         sid = self._next_id
         self._next_id += 1
         parent = self._open[-1] if self._open else None
@@ -72,7 +74,7 @@ class SpanLog:
         try:
             with jax.profiler.TraceAnnotation(name):
                 t0 = time.time_ns()
-                yield
+                yield attrs
                 t1 = time.time_ns()
         finally:
             self._open.pop()
@@ -109,10 +111,14 @@ def active_log() -> "SpanLog | None":
 
 @contextlib.contextmanager
 def span(name: str, **attrs):
-    """Annotate a host-side phase; logs to the active SpanLog if any."""
+    """Annotate a host-side phase; logs to the active SpanLog if any.
+
+    Yields the span's attribute dict: ``with span("x") as attrs:
+    attrs["hit"] = ...`` records an outcome known only inside the phase
+    (discarded when tracing is off)."""
     if _ACTIVE is not None:
-        with _ACTIVE.span(name, **attrs):
-            yield
+        with _ACTIVE.span(name, **attrs) as logged:
+            yield logged
     else:
         with jax.profiler.TraceAnnotation(name):
-            yield
+            yield attrs

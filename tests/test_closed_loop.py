@@ -16,6 +16,7 @@ import numpy as np
 import pytest
 from numpy.random import default_rng
 
+import jax
 import jax.numpy as jnp
 
 from _hyp import given, settings, st
@@ -23,6 +24,7 @@ from repro.configs.base import MeshConfig
 from repro.core import M1, AdaptiveEngine, Workload, snap_to_grid
 from repro.core.workload import FS_GRID, RS_GRID
 from repro.fleet import FleetController
+from repro.obs import trace as obs_trace
 from repro.telemetry import gradual_decay, stochastic_congestion
 
 SEG_GAP = 10.0
@@ -203,6 +205,107 @@ def test_engine_cache_survives_mask_change(monkeypatch):
     # one build per distinct world; the mask change after the eviction
     # re-keys the engine cache but reuses every cached dynamics table
     assert len(builds) == len(set(builds)) == len(worlds)
+
+
+def _device_rounds(eng, plan, *, cold=False):
+    """``plan``'s (arrivals, segments) as consecutive device-loop rounds of
+    ``eng``; ``cold`` clears its loop-table cache before every round. Per
+    round: the result, the program's inputs, final carry and ``ys`` on the
+    host, and the cache counter after the round."""
+    import repro.core.closed_loop as cl
+
+    run, calls = cl.run_closed_loop, []
+
+    def recording(*args):
+        final, ys = run(*args)
+        calls.append(jax.tree_util.tree_map(np.asarray, (args[:4], final, ys)))
+        return final, ys
+
+    rounds = []
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(cl, "run_closed_loop", recording)
+        for arrivals, segments in plan:
+            if cold:
+                eng._loop_tables.clear()
+                eng._loop_priors = None
+            res = eng.run(arrivals, segments=segments, device_loop=True)
+            rounds.append((res, calls[-1], dict(eng.loop_tables_stats)))
+    return rounds
+
+
+def _assert_rounds_identical(warm, cold):
+    for (res_w, arrays_w, _), (res_c, arrays_c, _) in zip(warm, cold,
+                                                          strict=True):
+        assert res_w == res_c
+        leaves_w, tree_w = jax.tree_util.tree_flatten(arrays_w)
+        leaves_c, tree_c = jax.tree_util.tree_flatten(arrays_c)
+        assert tree_w == tree_c
+        for a, b in zip(leaves_w, leaves_c, strict=True):
+            assert a.dtype == b.dtype
+            np.testing.assert_array_equal(a, b)
+
+
+def _fleet_engine(**kw):
+    return AdaptiveEngine([M1] * 3, prior=0.0, decay=0.997, ring_capacity=256,
+                          fleet=FleetController(mesh=MeshConfig()), **kw)
+
+
+def test_loop_tables_built_once_per_engine():
+    """Rounds after the first reuse the device loop's cluster, dynamics
+    stack and priors; inputs, final carry, ``ys`` and results stay
+    bit-identical to a twin that rebuilds them every round."""
+    plan = [(_replay(_segment(seed, 8), 4), 4) for seed in (3, 4, 5)]
+    log = obs_trace.enable_tracing()
+    try:
+        warm = _device_rounds(_fleet_engine(), plan)
+    finally:
+        obs_trace.disable_tracing()
+    assert [stats for *_, stats in warm] == [
+        {"hits": 0, "misses": 1}, {"hits": 1, "misses": 1},
+        {"hits": 2, "misses": 1}]
+    assert [s.attrs["cached"] for s in log.spans
+            if s.name == "closed_loop.pack.tables"] == [False, True, True]
+    cold = _device_rounds(_fleet_engine(), plan, cold=True)
+    assert cold[-1][-1] == {"hits": 0, "misses": 3}
+    _assert_rounds_identical(warm, cold)
+
+
+def test_loop_tables_key_on_worlds_not_mask(monkeypatch):
+    """A drift schedule's run builds its tables once per distinct tuple of
+    per-segment worlds; the eviction it causes changes only the carried
+    mask, so the second round takes hits alone, and decides as a twin
+    that rebuilds every round."""
+    import repro.core.engine as engine_mod
+
+    clusters = []
+    orig = engine_mod.PackedCluster.build
+
+    def counting(servers, *a, **kw):
+        clusters.append(tuple(servers))
+        return orig(servers, *a, **kw)
+
+    monkeypatch.setattr(engine_mod.PackedCluster, "build",
+                        staticmethod(counting))
+    segments, n_seg, failing = 6, 14, 1
+    drift = gradual_decay([M1] * 3, server=failing, rate=0.65, start=1,
+                          segments=segments)
+    # 6 and 5 segments: two distinct world tuples (one compilation: both
+    # pad to 8 segments of 14 arrivals)
+    plan = [(_replay(_segment(seed, n_seg), k), k)
+            for seed in (11, 12) for k in (segments, segments - 1)]
+    eng = _fleet_engine(drift=drift)
+    warm = _device_rounds(eng, plan)
+    assert any(ev.kind == "evict" for ev in
+               (ev for evs in warm[0][0].health for ev in evs))
+    assert [stats for *_, stats in warm] == [
+        {"hits": 0, "misses": 1}, {"hits": 0, "misses": 2},
+        {"hits": 1, "misses": 2}, {"hits": 2, "misses": 2}]
+    assert len(clusters) == 2
+    assert len(eng._loop_tables) == 2
+    assert len(eng._dyn_cache) == segments  # one table per distinct world
+    cold = _device_rounds(_fleet_engine(drift=drift), plan, cold=True)
+    assert cold[-1][-1] == {"hits": 0, "misses": 4}
+    _assert_rounds_identical(warm, cold)
 
 
 def test_device_loop_rejects_ragged_and_callbacks():
