@@ -123,6 +123,65 @@ def _pair_d(dem_i, dem_j, sens_j, cap):
     return 1.0 - np.prod(1.0 - sens_j * slow, axis=-1)
 
 
+class Scores:
+    """Each type's score, slack and feasibility on every server, over one
+    segment's changing cluster.
+
+    It reads the segment's per-server state arrays in place (``counts``,
+    ``comp``, ``col0``, ``maxd_now``), which only a place or a finish
+    changes, one server at a time, and the estimate ``D`` and activity
+    fixed for the segment. The costly term, the largest predicted
+    degradation on a server once a type-u task joins it (a max over the
+    types present there), is kept per (server, type) and recomputed only
+    where that server changed since (listed in ``touched``), or where the
+    type had not been asked for on it yet. Every element comes from the
+    same float64 expression as computed whole, so results do not depend on
+    the caching.
+    """
+
+    def __init__(self, ref: "Reference", comp_of, diagD, counts, comp, col0, maxd_now):
+        self.ref = ref
+        self.comp_of, self.diagD = comp_of, diagD
+        self.counts, self.comp, self.col0, self.maxd_now = counts, comp, col0, maxd_now
+        self.maxd = np.zeros(counts.shape)  # [m, T]
+        self.fresh = np.zeros(counts.shape, bool)
+        #: servers whose residents changed since the last ask; a list, as
+        #: most segments of a replay are never scored
+        self.touched: list[int] = []
+
+    def maxd_after(self, types) -> np.ndarray:
+        """[m, Q]: per server, the max over the types present there with
+        each of ``types`` added of the clipped additive degradation."""
+        if self.touched:
+            self.fresh[self.touched] = False
+            self.touched.clear()
+        for u in set(types.tolist()):
+            fresh = self.fresh[:, u]  # a view: marking it marks the table
+            if not fresh.all():
+                ss = np.flatnonzero(~fresh)
+                dpred = np.clip(self.col0[ss] + self.ref.D[ss, u] - self.diagD[ss],
+                                0.0, 1.0)  # [P, T]
+                present = self.counts[ss] > 0
+                present[:, u] = True
+                self.maxd[ss, u] = np.where(present, dpred, -np.inf).max(axis=1)
+                fresh[ss] = True
+        return self.maxd[:, types]
+
+    def __call__(self, types):
+        """Scores, slack and feasibility of each type on each server [Q, m]."""
+        ref = self.ref
+        maxd_after = self.maxd_after(types)
+        comp_t = self.comp_of[:, types]
+        budget = ref.budget[:, None]
+        active = ref.active[:, None]
+        cache_after = (self.comp[:, None] + comp_t) / budget
+        slack = np.where(active, np.minimum(ref.limit - maxd_after, 1.0 - cache_after),
+                         -np.inf)
+        feas = (maxd_after < ref.limit) & (cache_after <= 1.0) & active
+        sc = ref.q(0.5 * (comp_t / budget + maxd_after - self.maxd_now[:, None]))
+        return np.where(feas, sc, np.inf).T, slack.T, feas.T
+
+
 @dataclasses.dataclass
 class SegmentResult:
     """Per task of a segment (requeued work first): decisions and times."""
@@ -163,13 +222,18 @@ class Reference:
         self.L_prior_c = np.stack([np.log1p(-np.clip(p, 0.0, 1.0 - 1e-9)).T
                                    for p in priors])
         self.logb_prior = np.log(np.stack([self.tables[c].solo for c in self.cls]))
-        self.L = self.L_prior_c[self.cls].copy()  # [s, t, u]
+        # [s, t, u], C-contiguous: the estimator's dot products over a row
+        # sum in an order that depends on its strides
+        self.L = self.L_prior_c[self.cls].copy()
         self.log_b = self.logb_prior.copy()
         self.n_pair = np.zeros((m, Tn, Tn))
         self.n_base = np.zeros((m, Tn))
         self.D = np.zeros((m, Tn, Tn))  # [s, u, t] the scheduler's estimate
-        for s in range(m):
-            self._blend(s, np.arange(Tn))
+        # every server of a class starts from the same estimate: blend one
+        for c in range(len(names)):
+            of_c = np.flatnonzero(self.cls == c)
+            self._blend(of_c[0], np.arange(Tn))
+            self.D[of_c] = self.D[of_c[0]]
         self.level = np.zeros(m)
         self.exposure = np.zeros(m)
         self.active = np.ones(m, bool)
@@ -245,22 +309,8 @@ class Reference:
         gap = 0.0
         now, ai, draining = 0.0, 0, False
         trigger = np.nan  # the program's time of the last completion
-
-        def score(types):
-            """Scores, slack and feasibility of each type on each server [Q, m]."""
-            cache_after = (comp[:, None] + comp_of[:, types]) / self.budget[:, None]
-            dpred = np.clip(col0[:, None, :] + self.D[:, types, :] - diagD[:, None, :],
-                            0.0, 1.0)  # [m, Q, T]
-            present = np.repeat((counts > 0)[:, None, :], len(types), axis=1)
-            present[:, np.arange(len(types)), types] = True
-            maxd_after = np.where(present, dpred, -np.inf).max(axis=2)  # [m, Q]
-            slack = np.minimum(self.limit - maxd_after, 1.0 - cache_after)
-            slack = np.where(self.active[:, None], slack, -np.inf)
-            feas = ((maxd_after < self.limit) & (cache_after <= 1.0)
-                    & self.active[:, None])
-            sc = q(0.5 * (comp_of[:, types] / self.budget[:, None] + maxd_after
-                          - maxd_now[:, None]))
-            return np.where(feas, sc, np.inf).T, slack.T, feas.T
+        score = Scores(self, comp_of, diagD, counts, comp, col0, maxd_now)
+        touched = score.touched
 
         def judge(t, p):
             """The gap of putting type t on server p (p < 0: queueing it)."""
@@ -303,6 +353,7 @@ class Reference:
             # clip is monotone: the clipped max is the max, clipped
             maxd_now[s] = (min(max(float((col0[s] - diagD[s])[pres].max()), 0.0), 1.0)
                            if pres.any() else 0.0)
+            touched.append(s)
             ts = on[s]
             if ts:
                 tt = wtype[ts]

@@ -37,6 +37,31 @@ def test_reduce_counts_overlapping_ops_once_and_attributes_gaps():
     assert r.idle_gaps[1] == ("harness", pytest.approx(1.0))
 
 
+def test_gaps_name_the_innermost_sub_span():
+    """A gap inside a pack or dispatch sub-span is named for the sub-span,
+    and the busy time and window read as without them."""
+    ops = [Event("a", 0.0, 0.3), Event("a", 0.6, 1.4), Event("b", 2.9, 3.0)]
+    phases = [Event("bench.round", 0.0, 3.0),
+              Event("closed_loop.pack", 0.0, 0.5),
+              Event("closed_loop.dispatch", 0.5, 2.5),
+              Event("closed_loop.epilogue", 2.5, 3.0)]
+    subs = [Event("closed_loop.pack.arrivals", 0.0, 0.1),
+            Event("closed_loop.pack.tables", 0.1, 0.15),
+            Event("closed_loop.pack.state", 0.15, 0.5),
+            Event("closed_loop.dispatch.call", 0.5, 0.7),
+            Event("closed_loop.dispatch.wait", 0.7, 1.5),
+            Event("closed_loop.dispatch.fetch", 1.5, 2.5)]
+    assert {e.name for e in phases[1:] + subs} == set(tracing.PROGRAM_SPANS)
+    r = tracing.reduce(TraceData({"/device:X:0": ops}, phases + subs, {}))
+    bare = tracing.reduce(TraceData({"/device:X:0": ops}, phases, {}))
+    assert r.idle_gaps == [("closed_loop.dispatch.fetch", pytest.approx(1.5)),
+                           ("closed_loop.pack.state", pytest.approx(0.3))]
+    assert bare.idle_gaps == [("closed_loop.dispatch", pytest.approx(1.5)),
+                              ("closed_loop.pack", pytest.approx(0.3))]
+    assert (r.busy_s, r.window_s, r.rounds, r.device_ops) == \
+        (bare.busy_s, bare.window_s, bare.rounds, bare.device_ops)
+
+
 def test_reduce_finds_nothing_without_device_ops_or_rounds():
     host = [Event("bench.round", 0.0, 1.0)]
     assert tracing.reduce(TraceData({}, host, {})) is None
@@ -69,3 +94,31 @@ def test_reduction_of_a_trace_recorded_on_the_cpu(tmp_path):
     packs = [g for name, g in r.idle_gaps if name == "closed_loop.pack"]
     assert len(packs) >= 3 and min(sorted(packs)[-3:]) > 0.015
     assert r.idle_gaps[0][0] == "closed_loop.pack"
+
+
+def test_a_recorded_trace_keeps_the_sub_spans(tmp_path):
+    """``load_xplane`` keeps the sub-spans, so a gap in one is named for it."""
+    f = jax.jit(lambda x: jnp.sin(x) @ x)
+    x = jnp.ones((256, 256))
+    f(x).block_until_ready()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        for _ in range(3):
+            with jax.profiler.TraceAnnotation("bench.round"):
+                with jax.profiler.TraceAnnotation("closed_loop.dispatch"):
+                    with jax.profiler.TraceAnnotation("closed_loop.dispatch.call"):
+                        y = f(x)
+                    with jax.profiler.TraceAnnotation("closed_loop.dispatch.wait"):
+                        y.block_until_ready()
+                    with jax.profiler.TraceAnnotation("closed_loop.dispatch.fetch"):
+                        time.sleep(0.02)
+    finally:
+        jax.profiler.stop_trace()
+    path = next(tmp_path.glob("plugins/profile/*/*.xplane.pb"))
+    cpu_ops = lambda plane, lines: [ln for ln in lines if ln.startswith("tf_XLAPjRtCpuClient")] \
+        if plane == "/host:CPU" else []
+    data = tracing.load_xplane(path, device_lines=cpu_ops)
+    names = {e.name for e in data.host}
+    assert {"closed_loop.dispatch", "closed_loop.dispatch.fetch"} <= names
+    r = tracing.reduce(data)
+    assert r.idle_gaps[0][0] == "closed_loop.dispatch.fetch"

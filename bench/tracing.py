@@ -15,8 +15,12 @@ from collections import defaultdict
 from pathlib import Path
 
 ROUND_SPAN = "bench.round"
-#: host spans a gap can be attributed to, innermost first
-PROGRAM_SPANS = ("closed_loop.pack", "closed_loop.dispatch", "closed_loop.epilogue")
+#: host spans a gap can be attributed to: the program's phases of a round
+#: and the sub-spans that tile pack and dispatch (the innermost one wins)
+PROGRAM_SPANS = ("closed_loop.pack", "closed_loop.dispatch", "closed_loop.epilogue",
+                 "closed_loop.pack.arrivals", "closed_loop.pack.tables",
+                 "closed_loop.pack.state", "closed_loop.dispatch.call",
+                 "closed_loop.dispatch.wait", "closed_loop.dispatch.fetch")
 
 
 @dataclasses.dataclass(frozen=True)
