@@ -43,6 +43,7 @@ def tracked_functions() -> "dict[str, Callable]":
     return {
         "core.engine_jax.run_trace": engine_jax.run_trace,
         "core.closed_loop.run_closed_loop": closed_loop.run_closed_loop,
+        "core.closed_loop.host_view": closed_loop.host_view,
         "telemetry.estimator._update_device": estimator._update_device,
         "telemetry.estimator._update_bank": estimator._update_bank,
         "telemetry.estimator._scatter_jnp_jit": estimator._scatter_jnp_jit,

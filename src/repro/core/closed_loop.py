@@ -43,11 +43,13 @@ DESIGN.md section 13 for when to prefer it).
 from __future__ import annotations
 
 import dataclasses
+import math
 from functools import partial
 from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ..fleet.controller import fleet_step
 from ..fleet.detect import CusumState, _cusum_update
@@ -478,3 +480,66 @@ def run_closed_loop(
                   axis.rep(), carry_specs, axis.rep_tree(xs)),
         out_specs=(carry_specs, ys_specs))
     return mapped(cluster, dyn_stack, Lp_t, logb_priors, carry, xs)
+
+
+# What the host reads of one run: every SegmentOut leaf but ``n_requeued``
+# (no host code reads it), the ring cursor, and with a fleet controller
+# the routing and mask its mirror adopts. The bank, detector, ring buffer,
+# metrics frame and recorder stay on the device: the host adopts them as
+# device arrays and never reads them.
+_HOST_OUT = tuple(f for f in SegmentOut._fields if f != "n_requeued")
+_HOST_CARRY = ("ring_ptr", "ring_total")
+_HOST_FLEET = ("row_map", "read_row", "active")
+_WORD_DTYPES = (np.dtype(np.int32), np.dtype(np.float32), np.dtype(np.bool_))
+
+
+def _host_fields(final: LoopCarry, ys: SegmentOut, fleet: bool):
+    """(name, array) of everything :func:`host_view` packs, in buffer order."""
+    names = _HOST_CARRY + (_HOST_FLEET if fleet else ())
+    return ([(f, getattr(ys, f)) for f in _HOST_OUT]
+            + [(f, getattr(final, f)) for f in names])
+
+
+def host_view_layout(final: LoopCarry, ys: SegmentOut, fleet: bool
+                     ) -> tuple[tuple[str, int, tuple[int, ...], np.dtype], ...]:
+    """Where :func:`host_view` puts each field: ``(name, offset, shape,
+    dtype)``, offsets in 32-bit words. A function of the arrays' shapes and
+    dtypes alone, so the host reads it without touching the device."""
+    layout, off = [], 0
+    for name, a in _host_fields(final, ys, fleet):
+        dtype = np.dtype(a.dtype)
+        if dtype not in _WORD_DTYPES:
+            raise TypeError(f"host_view packs 32-bit words; {name} is {dtype}")
+        layout.append((name, off, tuple(a.shape), dtype))
+        off += math.prod(a.shape)
+    return tuple(layout)
+
+
+@partial(jax.jit, static_argnames=("fleet",))
+def host_view(final: LoopCarry, ys: SegmentOut, fleet: bool) -> jax.Array:
+    """Everything the host reads of one :func:`run_closed_loop` call as one
+    flat ``int32`` buffer, so the run reaches the host in one
+    device-to-host copy instead of one per array: each copy has a fixed
+    cost that dwarfs the payload of a few KB.
+
+    float32 fields travel as their bit patterns (``bitcast_convert_type``:
+    NaN and inf exact), bools widened to 0/1. :func:`host_view_layout`
+    gives each field's place; :func:`unpack_host_view` reads it back."""
+    return jnp.concatenate([
+        (jax.lax.bitcast_convert_type(a, jnp.int32) if a.dtype == jnp.float32
+         else a.astype(jnp.int32)).reshape(-1)
+        for _, a in _host_fields(final, ys, fleet)])
+
+
+def unpack_host_view(words: np.ndarray, layout) -> dict[str, np.ndarray]:
+    """The fields of a fetched :func:`host_view` buffer, by name: numpy
+    views of ``words`` with each field's shape and dtype, bit for bit."""
+    out = {}
+    for name, off, shape, dtype in layout:
+        w = words[off:off + math.prod(shape)]
+        if dtype == np.float32:
+            w = w.view(np.float32)
+        elif dtype == np.bool_:
+            w = w != 0
+        out[name] = w.reshape(shape)
+    return out

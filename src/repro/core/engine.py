@@ -1007,7 +1007,11 @@ class AdaptiveEngine:
         Host work is strictly prologue (:meth:`_pack_device_loop`) and
         epilogue (unpack per-segment results, mirror the final carry back
         into the host objects via ``FleetController.adopt_device_outcome`` /
-        ``PooledEstimatorBank.adopt_rows``). Per-segment ``EngineResult``s
+        ``PooledEstimatorBank.adopt_rows``). Everything the epilogue reads
+        reaches the host in one copy of ``closed_loop.host_view``'s buffer
+        (span ``closed_loop.dispatch.fetch``, attributes ``transfers`` and
+        ``bytes``); the bank, detector, ring buffer, metrics frame and
+        recorder are adopted as device arrays. Per-segment ``EngineResult``s
         carry no ``observations``/``stream_block``: the telemetry was
         consumed inside the program (the ring holds the bounded history).
 
@@ -1018,27 +1022,33 @@ class AdaptiveEngine:
         With ``metrics=True`` the MetricFrame rides the scan carry and the
         merged run frame is returned on ``AdaptiveResult.metrics``.
         """
-        from .closed_loop import run_closed_loop
+        from .closed_loop import (host_view, host_view_layout,
+                                  run_closed_loop, unpack_host_view)
 
         packed = self._pack_device_loop(arrivals, segments, metrics=metrics,
                                         record=record)
         m, R = len(self.servers), int(packed.carry.req_type.shape[0])
-        t0s, fc = packed.t0s, self.fleet
+        t0s, fc, fleet = packed.t0s, self.fleet, packed.config.fleet
         with obs_trace.span("closed_loop.dispatch", segments=segments, m=m,
                             s_cap=int(packed.xs.seg_valid.shape[0])):
             with obs_trace.span("closed_loop.dispatch.call"):
                 final, ys = run_closed_loop(*packed[:7])
+                # enqueued behind the program: what the host reads, packed
+                buf = host_view(final, ys, fleet=fleet)
             with obs_trace.span("closed_loop.dispatch.wait"):
-                jax.block_until_ready(ys)
-            with obs_trace.span("closed_loop.dispatch.fetch"):
-                ys = jax.tree_util.tree_map(np.asarray, ys)
+                jax.block_until_ready(buf)
+            with obs_trace.span("closed_loop.dispatch.fetch") as fetch:
+                words = np.asarray(buf)
+                fetch.update(transfers=1, bytes=int(words.nbytes))
+                out = unpack_host_view(words,
+                                       host_view_layout(final, ys, fleet))
 
         # failures surface before any state is adopted, leaving the host
         # objects where they were (the failed run never happened)
-        if ys.deadlock[:segments].any():
+        if out["deadlock"][:segments].any():
             raise RuntimeError(
                 "deadlock: queued workloads fit no empty server")
-        if ys.req_overflow[:segments].any():
+        if out["req_overflow"][:segments].any():
             raise RuntimeError(
                 f"eviction requeued more than one segment's worth of work "
                 f"({R} slots); run the host-alternating path")
@@ -1046,45 +1056,44 @@ class AdaptiveEngine:
         with obs_trace.span("closed_loop.epilogue", segments=segments):
             results, n_obs = [], []
             for k in range(segments):
-                nv = int(ys.n_valid[k])
+                nv = int(out["n_valid"][k])
                 t0 = t0s[k]
-                placement = ys.placement[k][:nv]
-                pt = ys.place_time[k][:nv].astype(np.float64)
-                ft = ys.finish_time[k][:nv].astype(np.float64)
+                placement = out["placement"][k][:nv]
+                pt = out["place_time"][k][:nv].astype(np.float64)
+                ft = out["finish_time"][k][:nv].astype(np.float64)
                 pt = np.where(pt >= 0.0, pt + t0, pt)
                 ft = np.where(np.isfinite(ft), ft + t0, ft)
                 results.append(EngineResult(
                     placements=tuple(int(p) if p != QUEUED else None
                                      for p in placement),
-                    was_queued=tuple(bool(q) for q in ys.was_queued[k][:nv]),
+                    was_queued=tuple(bool(q) for q in out["was_queued"][k][:nv]),
                     place_times=tuple(float(t) for t in pt),
                     finish_times=tuple(float(t) for t in ft),
-                    makespan=float(ys.makespan[k]) + t0,
-                    max_observed_degradation=float(ys.max_deg[k]),
+                    makespan=float(out["makespan"][k]) + t0,
+                    max_observed_degradation=float(out["max_deg"][k]),
                     backend="jax"))
-                n_obs.append(int(ys.used[k]))
+                n_obs.append(int(out["used"][k]))
 
             if fc is not None:
                 outcomes = [
-                    dict(segment=k, split_fired=ys.split_fired[k],
-                         split_stat=ys.split_stat[k],
-                         evict_fired=ys.evict_fired[k],
-                         evict_stat=ys.evict_stat[k],
-                         evict_route=ys.evict_route[k],
-                         active_after=ys.active_after[k])
+                    dict(segment=k, split_fired=out["split_fired"][k],
+                         split_stat=out["split_stat"][k],
+                         evict_fired=out["evict_fired"][k],
+                         evict_stat=out["evict_stat"][k],
+                         evict_route=out["evict_route"][k],
+                         active_after=out["active_after"][k])
                     for k in range(segments)]
                 per_seg = fc.adopt_device_outcome(
-                    final.bank, final.det, np.asarray(final.row_map),
-                    np.asarray(final.read_row), np.asarray(final.active),
-                    outcomes)
+                    final.bank, final.det, out["row_map"], out["read_row"],
+                    out["active"], outcomes)
                 health = [tuple(evs) for evs in per_seg]
             else:
                 self.bank._stacked = final.bank
                 self.bank._dirty = True
                 health = [() for _ in range(segments)]
             self.ring._buf = final.ring
-            self.ring.ptr = int(final.ring_ptr)
-            self.ring.total = int(final.ring_total)
+            self.ring.ptr = int(out["ring_ptr"])
+            self.ring.total = int(out["ring_total"])
             if record:
                 self.decisions.adopt(final.rec)
         return AdaptiveResult(tuple(results), tuple(n_obs), tuple(t0s),

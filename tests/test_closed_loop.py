@@ -12,6 +12,8 @@ the scheduler's score-margin tie collapse before it can reach a decision).
 """
 from __future__ import annotations
 
+import time
+
 import numpy as np
 import pytest
 from numpy.random import default_rng
@@ -319,3 +321,240 @@ def test_device_loop_rejects_ragged_and_callbacks():
     plain = AdaptiveEngine([M1] * 2, prior=0.0)
     with pytest.raises(ValueError, match="stream"):
         plain.run(arrivals, segments=2, device_loop=True)
+
+
+# -- one device-to-host copy per run (``closed_loop.host_view``) -------------
+
+_CARRY_READS = ("row_map", "read_row", "active", "ring_ptr", "ring_total")
+
+
+def _special_f32(rng, shape):
+    """float32 values with every bit pattern a copy could lose: NaNs (one
+    with a payload, one negative), +-inf, -0.0 and a subnormal."""
+    specials = np.array([0x7FC00123, 0xFFC00000, 0x7F800000, 0xFF800000,
+                         0x80000000, 0x00000001], np.uint32).view(np.float32)
+    a = rng.standard_normal(shape).astype(np.float32)
+    flat = a.reshape(-1)
+    flat[rng.choice(flat.size, len(specials), replace=False)] = specials
+    return a
+
+
+@pytest.mark.parametrize("fleet", [False, True])
+def test_host_view_round_trip(fleet):
+    """``host_view`` packs what the host reads into one int32 buffer, and
+    ``unpack_host_view`` gives every field back bit for bit: NaN payloads,
+    infinities and -0.0, ``QUEUED`` placements, mixed bools and padding
+    segments; ``n_requeued`` stays on the device, and so do the routing and
+    mask without a fleet controller."""
+    from repro.core.closed_loop import (
+        LoopCarry, SegmentOut, host_view, host_view_layout, unpack_host_view)
+    from repro.core.engine_jax import QUEUED
+
+    rng = default_rng(19)
+    S, real, n_cap, m = 8, 5, 11, 3  # segments 5-7 are padding
+    live = np.arange(S) < real
+    placement = rng.integers(QUEUED, m, (S, n_cap)).astype(np.int32)
+    placement[~live] = QUEUED
+    n_valid = np.where(live, rng.integers(1, n_cap + 1, S), 0).astype(np.int32)
+    ys = SegmentOut(
+        placement=placement, was_queued=rng.random((S, n_cap)) < 0.3,
+        place_time=_special_f32(rng, (S, n_cap)),
+        finish_time=_special_f32(rng, (S, n_cap)),
+        makespan=_special_f32(rng, (S,)), max_deg=_special_f32(rng, (S,)),
+        deadlock=np.zeros(S, bool), used=n_valid * 2, n_valid=n_valid,
+        n_requeued=rng.integers(0, 4, S).astype(np.int32),
+        req_overflow=live & (rng.random(S) < 0.5),
+        split_fired=rng.random((S, m)) < 0.5,
+        split_stat=_special_f32(rng, (S, m)),
+        evict_fired=rng.random((S, m)) < 0.5,
+        evict_stat=_special_f32(rng, (S, m)),
+        evict_route=rng.random((S, m)) < 0.5,
+        active_after=rng.random((S, m)) < 0.5)
+    final = LoopCarry(
+        bank=None, det=None, row_map=np.array([0, -1, 0], np.int32),
+        read_row=np.array([0, 0, 2], np.int32),
+        active=np.array([True, False, True]), seen=np.int32(7),
+        req_type=None, req_bytes=None, req_n=None, ring=None,
+        ring_ptr=np.int32(37), ring_total=np.int32(2**31 - 5))
+    ys_dev, final_dev = jax.tree_util.tree_map(jnp.asarray, (ys, final))
+
+    layout = host_view_layout(final_dev, ys_dev, fleet)
+    words = np.asarray(host_view(final_dev, ys_dev, fleet=fleet))
+    assert words.dtype == np.int32 and words.ndim == 1
+    assert words.size == sum(int(np.prod(shape)) for _, _, shape, _ in layout)
+    got = unpack_host_view(words, layout)
+
+    expect = {f: getattr(ys, f) for f in SegmentOut._fields
+              if f != "n_requeued"}
+    expect.update({f: getattr(final, f) for f in _CARRY_READS
+                   if fleet or f.startswith("ring")})
+    assert got.keys() == expect.keys()
+    for name, e in expect.items():
+        e = np.asarray(e)
+        g = got[name]
+        assert (g.dtype, g.shape) == (e.dtype, e.shape), name
+        assert np.array_equal(np.frombuffer(g.tobytes(), np.uint8),
+                              np.frombuffer(e.tobytes(), np.uint8)), name
+    with pytest.raises(TypeError, match="32-bit"):
+        host_view_layout(final_dev, ys_dev._replace(
+            makespan=np.zeros(S, np.float64)), fleet)
+
+
+def _per_array_reads(monkeypatch):
+    """Make the engine read a run as it did before ``host_view``: each
+    ``SegmentOut`` leaf and each carry field the epilogue needs by its own
+    ``np.asarray``, ignoring the packed buffer."""
+    import repro.core.closed_loop as cl
+
+    run, last = cl.run_closed_loop, []
+
+    def recording(*args):
+        last[:] = run(*args)
+        return tuple(last)
+
+    def per_array(words, layout):
+        final, ys = last
+        out = {f: np.asarray(a) for f, a in ys._asdict().items()}
+        out.update({f: np.asarray(getattr(final, f)) for f in _CARRY_READS})
+        return out
+
+    monkeypatch.setattr(cl, "run_closed_loop", recording)
+    monkeypatch.setattr(cl, "unpack_host_view", per_array)
+
+
+def _mirror(eng):
+    """The host objects a device-loop run writes back into, as numpy."""
+    fc = eng.fleet
+    bank = fc.pool.bank._stacked if fc is not None else eng.bank._stacked
+    out = dict(ring_ptr=eng.ring.ptr, ring_total=eng.ring.total,
+               device=[np.asarray(a) for a in jax.tree_util.tree_leaves(
+                   (bank, eng.ring._buf))])
+    if fc is not None:
+        out.update(row_of=fc.pool.row_of, read_row=fc.pool._read_row,
+                   active=fc.active_mask(), seen=fc._segments_seen,
+                   events=[(e.kind, e.server, e.segment, e.stat)
+                           for e in fc.events],
+                   det=[np.asarray(a) for a in fc.detector.state])
+    return out
+
+
+def _assert_mirrors_equal(a, b):
+    assert a.keys() == b.keys()
+    for k in a:
+        if k in ("device", "det"):
+            for x, y in zip(a[k], b[k], strict=True):
+                np.testing.assert_array_equal(x, y, err_msg=k)
+        elif isinstance(a[k], np.ndarray):
+            np.testing.assert_array_equal(a[k], b[k], err_msg=k)
+        else:
+            assert a[k] == b[k], k
+
+
+def _assert_results_equal(a, b):
+    assert (a.segments, a.n_obs, a.t_starts, a.health) == (
+        b.segments, b.n_obs, b.t_starts, b.health)
+    assert (a.metrics is None) == (b.metrics is None)
+    if a.metrics is not None:
+        for x, y in zip(jax.tree_util.tree_leaves(a.metrics),
+                        jax.tree_util.tree_leaves(b.metrics), strict=True):
+            np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+    assert (a.decisions is None) == (b.decisions is None)
+    if a.decisions is not None:
+        ca, cb = a.decisions.columns(), b.decisions.columns()
+        assert ca.keys() == cb.keys()
+        for k in ca:
+            np.testing.assert_array_equal(ca[k], cb[k], err_msg=k)
+
+
+@pytest.mark.parametrize("fleet", [True, False], ids=["fleet", "stream"])
+@pytest.mark.parametrize("obs", [False, True], ids=["plain", "metrics+record"])
+def test_one_fetch_per_run_matches_per_array_reads(fleet, obs):
+    """A device-loop run reads its results through one ``host_view``
+    buffer: the results and the host mirror after each of two rounds equal
+    a twin's that reads every array on its own (the engine before the
+    packed fetch), the fetch span records one transfer of the buffer's
+    bytes, and between the dispatch and the epilogue's end the host reads
+    exactly one device array, inside the fetch. Without metrics and the
+    recorder, the decisions also equal the host-alternating oracle's."""
+    from jax._src.array import ArrayImpl
+
+    segments, n_seg = 6, 14
+    drift = gradual_decay([M1] * 3, server=1, rate=0.65, start=1,
+                          segments=segments)
+    plan = [(_replay(_segment(seed, n_seg), k), k)
+            for seed, k in ((11, segments), (12, segments - 1))]
+
+    def engine(**kw):
+        if fleet:
+            return _fleet_engine(drift=drift, **kw)
+        return AdaptiveEngine([M1] * 3, prior=0.0, decay=0.997,
+                              ring_capacity=256, stream=True, **kw)
+
+    def run(eng, arrivals, k, device_loop=True):
+        return eng.run(arrivals, segments=k, device_loop=device_loop,
+                       metrics=obs, record=obs)
+
+    # the packed fetch, stamping every read of a device array on the host
+    # on the spans' clock: ``np.asarray`` takes the buffer protocol on the
+    # CPU, ``int()``/``float()``/``bool()`` take ``_value``
+    reads, value, buffer = [], ArrayImpl._value, ArrayImpl.__buffer__
+
+    def counted_value(self):
+        reads.append(time.time_ns())
+        return value.fget(self)
+
+    def counted_buffer(self, flags):
+        reads.append(time.time_ns())
+        return buffer(self, flags)
+
+    packed, log = engine(), obs_trace.enable_tracing()
+    try:
+        with pytest.MonkeyPatch.context() as mp:
+            mp.setattr(ArrayImpl, "_value", property(counted_value))
+            mp.setattr(ArrayImpl, "__buffer__", counted_buffer)
+            rounds = [(run(packed, a, k), _mirror(packed)) for a, k in plan]
+    finally:
+        obs_trace.disable_tracing()
+
+    def only(name):
+        return [s for s in log.spans if s.name == name]
+
+    fetches = only("closed_loop.dispatch.fetch")
+    assert len(fetches) == len(plan)
+    for dispatch, fetch, epilogue in zip(only("closed_loop.dispatch"), fetches,
+                                         only("closed_loop.epilogue"),
+                                         strict=True):
+        assert fetch.attrs["transfers"] == 1
+        # per padded segment (8): 4 arrays of 2 * n_seg arrivals, 6 scalars
+        # and 6 per-server arrays; the ring cursor; with a fleet the routing
+        # and mask
+        words = 8 * (4 * 2 * n_seg + 6 + 6 * 3) + 2 + 3 * 3 * fleet
+        assert fetch.attrs["bytes"] == 4 * words
+        inside = [t for t in reads if dispatch.start_ns <= t <= epilogue.end_ns]
+        assert len(inside) == 1
+        assert fetch.start_ns <= inside[0] <= fetch.end_ns
+
+    with pytest.MonkeyPatch.context() as mp:
+        _per_array_reads(mp)
+        twin = engine()
+        before = [(run(twin, a, k), _mirror(twin)) for a, k in plan]
+    for (res, mirror), (res_b, mirror_b) in zip(rounds, before, strict=True):
+        _assert_results_equal(res, res_b)
+        _assert_mirrors_equal(mirror, mirror_b)
+
+    if fleet and not obs:
+        assert any(e.kind == "evict" for evs in rounds[0][0].health
+                   for e in evs)
+    if not obs:
+        oracle = engine()
+        for (res, _), (arrivals, k) in zip(rounds, plan):
+            host = run(oracle, arrivals, k, device_loop=False)
+            for a, b in zip(host.segments, res.segments, strict=True):
+                assert a.placements == b.placements
+                assert a.was_queued == b.was_queued
+            assert host.n_obs == res.n_obs
+            assert [[(e.kind, e.server, e.segment) for e in evs]
+                    for evs in host.health] == [
+                [(e.kind, e.server, e.segment) for e in evs]
+                for evs in res.health]
+        assert oracle.ring.total == packed.ring.total
